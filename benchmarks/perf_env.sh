@@ -18,10 +18,6 @@ case "${XLA_FLAGS:-}" in
   *) export XLA_FLAGS="${XLA_FLAGS:+${XLA_FLAGS} }--xla_force_host_platform_device_count=${REPRO_HOST_DEVICES}" ;;
 esac
 
-# Persistent XLA compile cache: repeated benchmark processes skip
-# compilation for already-seen shape buckets.
-export REPRO_COMPILE_CACHE="${REPRO_COMPILE_CACHE:-./.jax_cache}"
-
 # tcmalloc, when installed, removes glibc-malloc contention from XLA's
 # host allocation paths.
 for _tc in /usr/lib/x86_64-linux-gnu/libtcmalloc.so.4 \
@@ -37,5 +33,4 @@ done
 unset _tc
 
 echo "perf env: XLA_FLAGS=${XLA_FLAGS}"
-echo "perf env: REPRO_COMPILE_CACHE=${REPRO_COMPILE_CACHE}"
 echo "perf env: LD_PRELOAD=${LD_PRELOAD:-<none>}"

@@ -6,9 +6,11 @@ Prints ``name,us_per_call,derived`` CSV rows.
 
 ``--perf-env`` applies the reproducible perf environment (the SNIPPETS
 XLA tuning idioms) *before* jax is imported: virtual host devices for
-the sharded replay path, tcmalloc when present, and the persistent
-compile cache.  ``benchmarks/perf_env.sh`` exports the same settings
-for interactive shells.
+the sharded replay path and tcmalloc when present.
+``benchmarks/perf_env.sh`` exports the same settings for interactive
+shells.  The persistent compile cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else at ``<checkout>/.jax_cache``
+(``repro.core.compile_cache``).
 
 Modules: config_space (§5.1), basket_sweep (Fig. 6-8),
 consolidation_sweep (Fig. 9), acceptance (Fig. 10-11),
@@ -63,8 +65,6 @@ def apply_perf_env() -> None:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={n_dev}"
         ).strip()
-    os.environ.setdefault("REPRO_COMPILE_CACHE",
-                          os.path.join(".", ".jax_cache"))
     tc = next((p for p in TCMALLOC_PATHS if os.path.exists(p)), None)
     if tc and tc not in os.environ.get("LD_PRELOAD", ""):
         # LD_PRELOAD can't retroactively affect a running interpreter:

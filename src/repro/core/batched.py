@@ -43,8 +43,9 @@ Scale path (hyperscale replay; see docs/ARCHITECTURE.md):
     the per-arrival scoring gathers run on fleet partitions with a cheap
     cross-shard argmax reconcile (decision-identical to this module);
   * ``score_backend="pallas"`` routes MCC/MECC scoring through the
-    Pallas kernels (``repro.kernels.policy_score``), with the
-    interpreter/jnp fallback auto-selected on CPU.
+    compiled Pallas kernels (``repro.kernels.policy_score``); ``"auto"``
+    takes them on a TPU for fleets they tile, and ``"pallas_interpret"``
+    runs them in interpret mode (the CPU test path).
 
 Feature parity with the sequential engine (validated decision-for-decision
 in tests/test_equivalence.py, including on mixed A30+A100+H100 clusters):
@@ -402,22 +403,23 @@ def replay_statics(events: EventTrace, policy: int, *,
                    telemetry: bool = False) -> ReplayStatics:
     """Resolve user cfg (including ``score_backend="auto"``) against the
     trace's shapes/fleet into a hashable :class:`ReplayStatics`."""
-    from ..kernels.policy_score import LANES
+    from ..kernels.policy_score import LANES, kernel_fits
     G = len(events.gpu_model_id)
     kernel_ok = (policy in (MCC, MECC) and len(events.models) == 1
-                 and G % LANES == 0)
+                 and kernel_fits(G))
     if score_backend == "auto":
-        # The fused kernels only pay off where they compile (TPU); on CPU
-        # the jnp table-gather path is the fast fallback.
+        # The fused kernels run compiled only on a TPU; elsewhere the jnp
+        # table gathers are the engine's path.
         score_backend = ("pallas" if kernel_ok and not num_shards
                          and jax.default_backend() == "tpu" else "tables")
     if score_backend != "tables":
         if not kernel_ok:
             raise ValueError(
                 f"score_backend={score_backend!r} needs a single-model "
-                f"fleet, policy MCC/MECC and num_gpus % {LANES} == 0 "
-                f"(got policy={policy}, M={len(events.models)}, G={G}); "
-                "bucket the trace (repro.core.bucketing.pad_events)")
+                f"fleet, policy MCC/MECC and num_gpus a multiple of "
+                f"{LANES} that the kernels can tile (got policy={policy}, "
+                f"M={len(events.models)}, G={G}); bucket the trace "
+                "(repro.core.bucketing.pad_events)")
         if num_shards:
             raise ValueError("Pallas scoring is not supported on the "
                              "sharded path; use score_backend='tables'")
@@ -553,8 +555,9 @@ def _kernel_pick(st: ReplayStatics, free, prof0, host_ok, mecc_w):
     from ..kernels.policy_score import (LANES, engine_ecc_scores,
                                        engine_mcc_scores)
     model = st.models[0]
-    interpret = (st.score_backend == "pallas_interpret"
-                 or jax.default_backend() != "tpu")
+    # Interpret mode only when asked for: an explicit "pallas" off a TPU
+    # fails to lower rather than silently interpreting.
+    interpret = st.score_backend == "pallas_interpret"
     if st.policy == MCC:
         cc = engine_mcc_scores(free, prof0, model=model,
                                interpret=interpret)

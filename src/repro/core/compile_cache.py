@@ -7,10 +7,10 @@ Two cooperating layers keep hyperscale sweeps compile-bound only once:
     jit cache then holds one *executable* per argument-shape signature,
     i.e. per shape bucket (``repro.core.bucketing``), so the effective
     replay cache key is ``(bucket_shape, policy, cfg, model-set)``;
-  * JAX's persistent compilation cache (on-disk), enabled when
-    ``REPRO_COMPILE_CACHE`` (or the standard ``JAX_COMPILATION_CACHE_DIR``)
-    names a directory, so repeated *processes* — CI runs, sweep drivers —
-    also skip XLA for already-seen buckets.
+  * JAX's persistent compilation cache (on-disk), in the directory that
+    ``JAX_COMPILATION_CACHE_DIR`` names or else at ``<checkout>/.jax_cache``,
+    so repeated *processes* — CI runs, sweep drivers — also skip XLA for
+    already-seen buckets.
 
 This module holds no jax arrays, only callables, so it is safe to import
 before device initialization.
@@ -27,6 +27,11 @@ _RUN_CACHE: "OrderedDict[Any, Callable]" = OrderedDict()
 _STATS = {"hits": 0, "misses": 0, "evictions": 0}
 _MAX_ENTRIES: Optional[int] = None
 _PERSISTENT_DIR: str = ""
+# A fixed path (the cache key includes it): the checkout root, found from
+# this file (src/repro/core/), never from the current directory.
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
 def cached_replay_fn(key: Any, build: Callable[[], Callable]) -> Callable:
@@ -77,24 +82,22 @@ def clear_cache() -> None:
     _STATS["hits"] = _STATS["misses"] = _STATS["evictions"] = 0
 
 
-def ensure_persistent_cache(path: str | None = None) -> str:
-    """Point JAX's persistent compilation cache at ``path`` (or the
-    ``REPRO_COMPILE_CACHE`` / ``JAX_COMPILATION_CACHE_DIR`` env vars).
-    No-ops when no directory is configured.  Returns the active dir
-    ('' when disabled).  Idempotent; cheap to call per replay."""
+def ensure_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, places it (JAX
+    reads the variable itself; no other directory is set); otherwise it
+    is ``<checkout>/.jax_cache``.  Idempotent; cheap to call per replay."""
     global _PERSISTENT_DIR
-    path = (path or os.environ.get("REPRO_COMPILE_CACHE")
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR") or "")
-    if path and path != _PERSISTENT_DIR:
+    if not _PERSISTENT_DIR:
+        path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not path:
+            path = _DEFAULT_DIR
+            jax.config.update("jax_compilation_cache_dir", path)
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        try:
-            # Replay scans compile in ~0.5 s; cache them all, not just
-            # the >1 s default.
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except AttributeError:  # knob renamed across jax versions
-            pass
+        # Replay scans compile in ~0.5 s; cache them all, not just the
+        # >1 s default.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
         _PERSISTENT_DIR = path
     return _PERSISTENT_DIR
 

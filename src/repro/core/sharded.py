@@ -3,7 +3,7 @@
 The replay scan is inherently sequential over events, but the per-arrival
 work — gathering feasibility and scores for every GPU — is embarrassingly
 parallel over the fleet.  This module runs the *same* scan body
-(``repro.core.batched._scan_fn``) under ``jax.experimental.shard_map``
+(``repro.core.batched._scan_fn``) under ``jax.shard_map``
 with the cluster state replicated on every shard and only the expensive
 per-arrival table gathers computed on each shard's contiguous GPU slice:
 
@@ -49,7 +49,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..sim.metrics import SimResult
@@ -143,16 +142,19 @@ def grmu_select_sharded(T, mid, free, pids, is_heavy, host_ok, basket,
 # ---------------------------------------------------------------------------
 
 def fleet_mesh(num_shards: Optional[int] = None) -> Mesh:
-    """1-D fleet mesh over the first ``num_shards`` visible devices.  On
-    CPU, visible-device count comes from
+    """1-D fleet mesh over the first ``num_shards`` visible devices: one
+    shard per chip on a TPU host; on CPU, visible-device count comes from
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
     devs = jax.devices()
     k = num_shards or len(devs)
     if k > len(devs):
         raise ValueError(
-            f"num_shards={k} but only {len(devs)} devices are visible; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count "
-            "(benchmarks/run.py --perf-env) before importing jax")
+            f"num_shards={k} but only {len(devs)} "
+            f"{devs[0].platform} devices are visible: the host has fewer "
+            "chips than shards, or on CPU "
+            "XLA_FLAGS=--xla_force_host_platform_device_count is unset or "
+            "too small (it must be set before jax is imported; "
+            "benchmarks/run.py --perf-env sets it)")
     return Mesh(np.array(devs[:k]), (FLEET_AXIS,))
 
 
@@ -174,9 +176,9 @@ def make_sharded_replay(events: EventTrace, policy: int,
                         axis_name=FLEET_AXIS, num_shards=k, **cfg)
 
     def build():
-        body = shard_map(functools.partial(_scan_fn, st), mesh=mesh,
-                         in_specs=(P(), P(), P()), out_specs=P(),
-                         check_rep=False)
+        body = jax.shard_map(functools.partial(_scan_fn, st), mesh=mesh,
+                             in_specs=(P(), P(), P()), out_specs=P(),
+                             check_vma=False)
         return jax.jit(body, donate_argnums=(0,))
 
     jfn = compile_cache.cached_replay_fn((st, k, "shard"), build)

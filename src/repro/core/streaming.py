@@ -124,11 +124,11 @@ def make_chunked_replay(events: EventTrace, policy: int, *,
                             axis_name=SH.FLEET_AXIS, num_shards=k, **cfg)
 
         def build_chunk():
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
-            body = shard_map(functools.partial(_chunk_fn, st), mesh=mesh,
-                             in_specs=(P(), P(), P(), P()), out_specs=P(),
-                             check_rep=False)
+            body = jax.shard_map(functools.partial(_chunk_fn, st),
+                                 mesh=mesh,
+                                 in_specs=(P(), P(), P(), P()),
+                                 out_specs=P(), check_vma=False)
             return jax.jit(body, donate_argnums=(0,))
 
         chunk_key = (st, k, "shard-chunk", chunk_events)

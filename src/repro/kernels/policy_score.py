@@ -25,6 +25,10 @@ from ..core.mig import A100_40GB, DeviceModel
 
 BLOCK_ROWS = 64
 LANES = 128
+# Tallest whole-array tile taken when no multiple of 8 divides the row
+# count: the v5e compiler refuses a 4,004-row tile (VMEM overflow) and
+# accepts a 2,004-row one.
+MAX_TILE_ROWS = 1024
 
 
 def _cc_of(m, slot_masks):
@@ -66,12 +70,20 @@ def _ecc_kernel(model: DeviceModel, profile_idx: int, mask_ref, probs_ref,
 
 
 def _block_rows(rows: int) -> int:
-    """Largest tile height <= BLOCK_ROWS that divides ``rows`` (any
-    power-of-two row count down to 1 works — bucketed fleets are pow2)."""
-    br = min(BLOCK_ROWS, rows)
-    while rows % br:
-        br -= 1
-    return br
+    """Tile height for a (rows, 128) mask array that the TPU compiler
+    accepts: the largest multiple of 8 <= BLOCK_ROWS dividing ``rows``,
+    else the whole array as one tile."""
+    for br in range(BLOCK_ROWS, 0, -8):
+        if rows % br == 0:
+            return br
+    return rows
+
+
+def kernel_fits(num_gpus: int) -> bool:
+    """Whether the engine kernels compile for a fleet of ``num_gpus``:
+    whole 128-lane rows, tiled by :func:`_block_rows` within VMEM."""
+    return (num_gpus % LANES == 0
+            and _block_rows(num_gpus // LANES) <= MAX_TILE_ROWS)
 
 
 def mcc_score_pallas(masks2d: jax.Array, profile_idx: int, *,
@@ -157,4 +169,5 @@ def engine_ecc_scores(free: jax.Array, profile, probs_row: jax.Array, *,
 
 
 __all__ = ["mcc_score_pallas", "ecc_score_pallas", "engine_mcc_scores",
-           "engine_ecc_scores", "BLOCK_ROWS", "LANES"]
+           "engine_ecc_scores", "kernel_fits", "BLOCK_ROWS", "LANES",
+           "MAX_TILE_ROWS"]

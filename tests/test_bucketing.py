@@ -110,6 +110,16 @@ def test_pallas_backend_matches_tables(policy):
     assert_same_replay(rt, rp)
 
 
+def test_kernels_interpret_only_when_asked():
+    """Off a TPU, "auto" takes the table path and an explicit "pallas"
+    fails to lower instead of silently running the interpreter."""
+    cluster, vms = random_scenario(2)
+    pv = pad_events(B.build_events(vms, cluster), min_gpus=128)
+    assert B.replay_statics(pv, B.MCC).score_backend == "tables"
+    with pytest.raises(Exception, match="interpret"):
+        B.replay(pv, B.MCC, score_backend="pallas")
+
+
 def test_pallas_backend_requires_lane_aligned_single_model():
     cluster, vms = hetero_scenario(0)          # M=3 fleet
     pv = pad_events(B.build_events(vms, cluster), min_gpus=128)

@@ -110,3 +110,31 @@ def test_kernel_mcc_property(mask_list, pi):
     masks = np.array(mask_list, np.int32)
     got = np.asarray(mcc_scores(jnp.asarray(masks), pi))
     np.testing.assert_array_equal(got, T.CC_AFTER_TABLE[masks, pi])
+
+
+# ---------------------------------------------------------------------------
+# Engine-kernel tiling: what the TPU compiler accepts
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows,want", [
+    (100, 100),      # no multiple of 8 divides 100: one whole-array tile
+    (1000, 40),      # largest multiple of 8 <= 64 dividing 1000
+    *[(1 << k, min(1 << k, 64)) for k in range(12)],   # pow2 fleets
+])
+def test_block_rows_is_a_multiple_of_8_or_the_whole_array(rows, want):
+    from repro.kernels.policy_score import BLOCK_ROWS, _block_rows
+    br = _block_rows(rows)
+    assert br == want
+    assert rows % br == 0 and br <= max(rows, BLOCK_ROWS)
+    assert br % 8 == 0 or br == rows
+
+
+@pytest.mark.parametrize("num_gpus,fits", [
+    (2048, True), (12800, True), (128 * 1000, True),
+    (128 * 1004, True),            # a 1,004-row whole tile
+    (128 * 4004, False),           # a 4,004-row tile overflows VMEM
+    (2000, False),                 # not whole 128-lane rows
+])
+def test_kernel_fits_agrees_with_tiling(num_gpus, fits):
+    from repro.kernels.policy_score import kernel_fits
+    assert kernel_fits(num_gpus) == fits

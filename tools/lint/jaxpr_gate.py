@@ -122,7 +122,6 @@ def trace_variant(events, policy_id: int, policy_name: str,
             closed = jax.make_jaxpr(functools.partial(_chunk_fn, st))(
                 init_state(ev, st), chunk, rest, cap)
         elif variant == "sharded":
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             if len(jax.devices()) < NUM_SHARDS:
                 raise RuntimeError(
@@ -134,9 +133,9 @@ def trace_variant(events, policy_id: int, policy_name: str,
             st = replay_statics(ev, policy_id, score_backend="tables",
                                 axis_name=SH.FLEET_AXIS,
                                 num_shards=NUM_SHARDS, **kw)
-            body = shard_map(functools.partial(_scan_fn, st), mesh=mesh,
-                             in_specs=(P(), P(), P()), out_specs=P(),
-                             check_rep=False)
+            body = jax.shard_map(functools.partial(_scan_fn, st),
+                                 mesh=mesh, in_specs=(P(), P(), P()),
+                                 out_specs=P(), check_vma=False)
             closed = jax.make_jaxpr(body)(
                 init_state(ev, st), trace_arrays(ev), cap)
         else:
