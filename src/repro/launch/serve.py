@@ -12,8 +12,9 @@ the service with backpressure (a full queue sheds to ``drain``), flushes
 to the horizon, and optionally verifies the decisions against an offline
 replay of the same order (``--verify``) — the compile-once/serve-many
 parity contract.  ``--checkpoint-dir`` snapshots final state through
-``repro.launch.checkpoint``; ``--obs`` records ``serve.batch`` spans and
-``service`` governor events through the flight recorder.
+``repro.launch.checkpoint``; ``--obs`` records each micro-batch's
+``serve.*`` span tree and ``service`` governor events through the flight
+recorder.
 """
 from __future__ import annotations
 

@@ -15,24 +15,31 @@ Every line in the JSONL file is one record with ``schema`` (the
 ``SCHEMA_VERSION`` of ``repro.obs.inscan``), ``kind`` and ``run_id``:
 
   ``meta``       run header (wall time, caller-provided metadata)
-  ``span``       a named wall-clock span (``name``, ``dur_s``, extras
-                 such as ``index``/``nbytes`` for chunk steps) — also
-                 wrapped in ``jax.profiler.TraceAnnotation`` so spans
-                 line up with XLA events in a profiler trace
+  ``span``       a named wall-clock span: ``name``; ``t0``/``t1``, its
+                 start and end on ``time.perf_counter()``; ``dur_s``
+                 (``t1 - t0``); ``id``, a sequence number of the
+                 recorder; ``parent``, the ``id`` of the span open
+                 around it (None at the top); and the caller's fields
+                 (``index``/``nbytes`` for chunk steps, ``batch``/
+                 ``rows``/... for served micro-batches).  Each span is
+                 also a ``jax.profiler.TraceAnnotation``, so a profiler
+                 capture holds the same interval on its own clock
   ``cache``      compile-cache hits/misses/evictions/entries snapshot
   ``result``     a SimResult summary + rejection-reason tally
   ``telemetry``  a full ``ReplayTelemetry`` payload (in-scan plane)
   ``service``    a placement-service control-plane event (admission
                  governor tier switches, checkpoint/restore) — emitted
-                 by ``repro.serve.placement`` alongside ``serve.batch``
+                 by ``repro.serve.placement`` beside its ``serve.*``
                  spans
 
-Spans measure *dispatch* wall-clock: jax executes asynchronously, so a
-chunk-step span is the host-side cost of submitting (and, under donation
-back-pressure, partially waiting on) that chunk — end-to-end device time
-comes from the profiler trace.  ``REPRO_TRACE=1`` additionally captures
-a ``jax.profiler.start_trace`` session next to the JSONL file (or at
-``REPRO_TRACE_DIR``) for TensorBoard/Perfetto.
+Records are kept in memory and the file is written once, by
+:meth:`Recorder.close` (the :func:`record` block's exit), so a recorded
+loop makes no file write.  Spans measure *dispatch* wall-clock: jax
+executes asynchronously, so a chunk-step span is the host-side cost of
+submitting (and, under donation back-pressure, partially waiting on)
+that chunk — end-to-end device time comes from a profiler trace, which
+whoever opens the window starts (``jax.profiler``); the recorder starts
+none.
 """
 from __future__ import annotations
 
@@ -40,7 +47,7 @@ import contextlib
 import json
 import os
 import time
-from typing import Iterator, Optional
+from typing import Dict, Iterator, List, Optional
 
 import jax
 
@@ -55,42 +62,45 @@ def active() -> Optional["Recorder"]:
 
 
 class Recorder:
-    """Appends schema-versioned JSONL records; see the module docstring.
-    Prefer the :func:`record` context manager, which also installs the
-    recorder as the process-active one so engine loops emit spans."""
+    """Keeps schema-versioned records in memory and writes them as JSONL
+    at :meth:`close`; see the module docstring.  Prefer the
+    :func:`record` context manager, which also installs the recorder as
+    the process-active one so engine loops emit spans."""
 
     def __init__(self, path, *, run_id: Optional[str] = None,
                  meta: Optional[dict] = None):
         self.path = str(path)
         self.run_id = run_id or f"run-{os.getpid()}-{int(time.time())}"
-        self._fh = open(self.path, "a")
-        self._tracing = False
+        self.records: List[dict] = []
+        self._next_id = 0
+        self._open: List[int] = []   # ids of the open spans, outermost first
+        self._closed = False
         self.emit("meta", time_unix=time.time(), **(meta or {}))
-        if os.environ.get("REPRO_TRACE") == "1":
-            trace_dir = os.environ.get(
-                "REPRO_TRACE_DIR",
-                os.path.join(os.path.dirname(self.path) or ".",
-                             "jax_trace"))
-            jax.profiler.start_trace(trace_dir)
-            self._tracing = True
-            self.emit("trace_started", trace_dir=trace_dir)
 
     def emit(self, kind: str, **fields) -> None:
         rec = {"schema": SCHEMA_VERSION, "kind": kind,
                "run_id": self.run_id}
         rec.update(fields)
-        self._fh.write(json.dumps(rec) + "\n")
-        self._fh.flush()
+        self.records.append(rec)
 
     @contextlib.contextmanager
-    def span(self, name: str, **fields) -> Iterator[None]:
-        """Time a host-side region; doubles as a profiler annotation so
-        the span is visible in a ``REPRO_TRACE=1`` capture."""
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(name):
-            yield
-        self.emit("span", name=name,
-                  dur_s=time.perf_counter() - t0, **fields)
+    def span(self, name: str, **fields) -> Iterator[Dict[str, object]]:
+        """Time a host-side region, nested in the span open around it.
+        Yields the record's field dict: what the caller adds to it
+        inside the block (counts known only at the end) is recorded."""
+        sid = self._next_id
+        self._next_id += 1
+        parent = self._open[-1] if self._open else None
+        self._open.append(sid)
+        try:
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(name):
+                yield fields
+            t1 = time.perf_counter()
+        finally:
+            self._open.pop()
+        self.emit("span", name=name, t0=t0, t1=t1, dur_s=t1 - t0, id=sid,
+                  parent=parent, **fields)
 
     def cache_stats(self) -> None:
         """Snapshot the replay compile cache (hits/misses/evictions)."""
@@ -113,11 +123,12 @@ class Recorder:
         self.emit("service", event=event, **fields)
 
     def close(self) -> None:
-        if self._tracing:
-            jax.profiler.stop_trace()
-            self._tracing = False
-        if not self._fh.closed:
-            self._fh.close()
+        """Append every record to the file, once."""
+        if self._closed:
+            return
+        self._closed = True
+        with open(self.path, "a") as fh:
+            fh.writelines(json.dumps(r) + "\n" for r in self.records)
 
 
 @contextlib.contextmanager

@@ -35,7 +35,8 @@ cached decision step per tier's ``ReplayStatics``); the ``"ILP"`` tier
 runs the rolling-horizon :class:`~repro.core.policies.ILPPolicy` against
 an object-level ``Cluster`` rebuilt from the same canonical state
 snapshot that moves between tiers.  Switches are recorded through the
-flight recorder (``serve.batch`` spans + ``service`` JSONL records).
+flight recorder (``service`` JSONL records beside the ``serve.*`` span
+tree of every micro-batch; see :meth:`PlacementService._drain_batch_array`).
 
 Checkpoint/restore rides ``repro.launch.checkpoint``: the canonical
 snapshot (carry + host-side VM/arrival tables + stream counters) is an
@@ -44,6 +45,7 @@ the same config restores mid-stream and continues bit-identically.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -69,6 +71,14 @@ _EPS = 1e-9
 # The object-backed oracle tier (rolling-horizon MILP); every other tier
 # name must be a registry policy id (FF/BF/MCC/MECC/GRMU).
 ILP_TIER = "ILP"
+
+# What a micro-batch enters in place of ``Recorder.span`` when no
+# recorder is installed: one shared no-op, so nothing is built per span.
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str, **fields) -> contextlib.nullcontext:
+    return _NO_SPAN
 
 
 @dataclasses.dataclass
@@ -110,13 +120,16 @@ class ServeConfig:
 @dataclasses.dataclass(frozen=True)
 class Decision:
     """One arrival's placement decision.  ``latency_s`` is submit ->
-    decision-ready wall time (queue wait + kernel + readback)."""
+    decision-ready wall time (queue wait + kernel + readback); ``batch``
+    numbers the service's micro-batch that made it, as the ``batch``
+    field of that batch's ``serve.drain_batch`` span does."""
     vm_id: int
     accepted: bool
     gpu: int                  # global GPU index, -1 when rejected
     start: int                # start block on the chosen GPU
     tier: str                 # tier that made the decision
     latency_s: float
+    batch: int = -1
 
 
 class Governor:
@@ -190,6 +203,25 @@ def _skeleton_trace(models: Tuple[DeviceModel, ...],
         cpu_cap=np.asarray(cpu_cap, np.float32),
         ram_cap=np.asarray(ram_cap, np.float32),
         step_hours=step_hours)
+
+
+@dataclasses.dataclass
+class _Rows:
+    """One array-tier micro-batch popped from the queue: the step's
+    ``E`` event rows, of which the first ``n`` are real, the ingest rows
+    of its ``n_new`` arrivals, and each arrival's (row, slot, vm_id,
+    submit stamp)."""
+    kind: np.ndarray
+    vi: np.ndarray
+    prof: np.ndarray
+    tim: np.ndarray
+    idx: np.ndarray
+    batch_vi: np.ndarray
+    g_vm: np.ndarray
+    g_arr: np.ndarray
+    n: int
+    n_new: int
+    pending: List[Tuple[int, int, int, float]]
 
 
 def _ingest_fn():
@@ -335,6 +367,7 @@ class PlacementService:
                                                for n in self._tier_names}
         self.switch_events: List[dict] = []
         self._ckpt_seq = 0
+        self._n_batches = 0         # micro-batches drained so far
 
         # Object-tier state (populated by _enter_object).
         self._cluster: Optional[Cluster] = None
@@ -763,22 +796,72 @@ class PlacementService:
 
     # -- the micro-batch ---------------------------------------------------
     def _drain_batch(self) -> List[Decision]:
+        batch = self._n_batches
+        self._n_batches += 1
         if self.tier_name == ILP_TIER:
-            return self._drain_batch_object()
-        return self._drain_batch_array()
+            return self._drain_batch_object(batch)
+        return self._drain_batch_array(batch)
 
-    def _drain_batch_array(self) -> List[Decision]:
+    def _drain_batch_array(self, batch: int) -> List[Decision]:
+        """One array-tier micro-batch.  With a recorder installed it is
+        recorded as this span tree (fields after the names)::
+
+            serve.drain_batch   batch, tier
+              serve.pop         rows, arrivals, wait_sum_s, batch_rows
+              serve.batch       tier, rows, arrivals: the round trip
+                serve.ingest    (only with arrivals)
+                serve.step
+                serve.readback
+              serve.emit
+
+        An arrival's wait is the time from its submit to the start of
+        the pop; ``batch_rows`` is ``E``, the rows the step scans.
+        Without one, each span is the shared no-op and no clock is read
+        for the waits."""
+        rec = obs_recorder.active()
+        span = rec.span if rec is not None else _no_span
+        tier = self.tier_name
+        with span("serve.drain_batch", batch=batch, tier=tier):
+            with span("serve.pop") as f:
+                t_pop = time.perf_counter() if rec is not None else 0.0
+                rows = self._pop_rows()
+                if rec is not None:
+                    f.update(rows=rows.n, arrivals=len(rows.pending),
+                             wait_sum_s=sum(t_pop - p[3]
+                                            for p in rows.pending),
+                             batch_rows=self._batch_rows)
+            if rows.n == 0:
+                return []
+            with span("serve.batch", tier=tier, rows=rows.n,
+                      arrivals=len(rows.pending)):
+                if rows.n_new:
+                    with span("serve.ingest"):
+                        self._ingest_rows(rows)
+                with span("serve.step"):
+                    out = self._step_rows(rows)
+                with span("serve.readback"):
+                    got = jax.device_get(out)
+            with span("serve.emit"):
+                return self._emit(rows, got, batch)
+
+    def _pop_rows(self) -> _Rows:
+        """Pop up to ``E`` rows from the queue into step inputs: step
+        ends where a request's bucket passes the current one, then the
+        request's own row."""
         E = self._batch_rows
-        kind = np.full(E, B.PAD, np.uint8)
-        vi = np.zeros(E, np.int32)
-        prof = np.zeros(E, np.int16)
-        tim = np.zeros(E, np.float32)
-        idx = np.zeros(E, np.int32)
-        batch_vi = np.full(E, self._Ncap, np.int32)
-        # Fixed-shape ingest rows (sentinel slots drop).
-        g_vm = np.full(E, self._Ncap, np.int32)
-        g_arr = np.full(E, self._Acap, np.int32)
-        pending: List[Tuple[int, int, int, float]] = []
+        rows = _Rows(
+            kind=np.full(E, B.PAD, np.uint8), vi=np.zeros(E, np.int32),
+            prof=np.zeros(E, np.int16), tim=np.zeros(E, np.float32),
+            idx=np.zeros(E, np.int32),
+            batch_vi=np.full(E, self._Ncap, np.int32),
+            # Fixed-shape ingest rows (sentinel slots drop).
+            g_vm=np.full(E, self._Ncap, np.int32),
+            g_arr=np.full(E, self._Acap, np.int32),
+            n=0, n_new=0, pending=[])
+        kind, vi, prof, tim, idx = (rows.kind, rows.vi, rows.prof,
+                                    rows.tim, rows.idx)
+        batch_vi, g_vm, g_arr = rows.batch_vi, rows.g_vm, rows.g_arr
+        pending = rows.pending
         n = 0
         n_new = 0
         while n < E:
@@ -814,43 +897,45 @@ class PlacementService:
                 prof[n] = self._h_vm_pids[slot, 0]
                 tim[n] = np.float32(self._step_t)
             n += 1
-        if n == 0:
-            return []
-        tier = self.tier_name
-        rec = obs_recorder.active()
-        span = (rec.span("serve.batch", tier=tier, rows=n,
-                         arrivals=len(pending))
-                if rec is not None else _null_ctx())
-        with span:
-            if n_new:
-                # Scatter the new arrivals' table rows before the
-                # decision kernel reads them (gathers by slot sentinel
-                # drop the padding rows).
-                self._rest = self._ingest(
-                    self._rest, g_vm[:E],
-                    self._h_vm_pids[np.minimum(g_vm, self._Ncap - 1)],
-                    self._h_vm_heavy[np.minimum(g_vm, self._Ncap - 1)],
-                    self._h_vm_res[np.minimum(g_vm, self._Ncap - 1)],
-                    g_arr[:E],
-                    self._h_arr_times[np.minimum(g_arr,
-                                                 self._Acap - 1)],
-                    self._h_arr_pids[np.minimum(g_arr,
-                                                self._Acap - 1)])
-            ev = dict(kind=kind, vm_index=vi, profile=prof, time=tim,
-                      idx=idx)
-            self._state, rows = self._step_fn(
-                self._state, ev, self._rest, self._cap, batch_vi)
-            rows = jax.device_get(rows)
+        rows.n, rows.n_new = n, n_new
+        return rows
+
+    def _ingest_rows(self, rows: _Rows) -> None:
+        """Scatter the new arrivals' table rows before the decision
+        kernel reads them (gathers by slot sentinel drop the padding
+        rows)."""
+        g_vm, g_arr = rows.g_vm, rows.g_arr
+        vm = np.minimum(g_vm, self._Ncap - 1)
+        arr = np.minimum(g_arr, self._Acap - 1)
+        self._rest = self._ingest(
+            self._rest, g_vm, self._h_vm_pids[vm], self._h_vm_heavy[vm],
+            self._h_vm_res[vm], g_arr, self._h_arr_times[arr],
+            self._h_arr_pids[arr])
+
+    def _step_rows(self, rows: _Rows):
+        """Dispatch the decision step on the batch; returns its per-row
+        (gpu, start, accepted) output, still on the device."""
+        ev = dict(kind=rows.kind, vm_index=rows.vi, profile=rows.prof,
+                  time=rows.tim, idx=rows.idx)
+        self._state, out = self._step_fn(
+            self._state, ev, self._rest, self._cap, rows.batch_vi)
+        return out
+
+    def _emit(self, rows: _Rows, got: np.ndarray,
+              batch: int) -> List[Decision]:
+        """Turn the read-back rows into the arrivals' decisions, then let
+        the governor see the batch."""
         t_done = time.perf_counter()
+        tier = self.tier_name
         out: List[Decision] = []
-        for j, slot, vm_id, enq in pending:
-            r = rows[j]
+        for j, slot, vm_id, enq in rows.pending:
+            r = got[j]
             acc = int(r[2]) > 0
             self._h_accepted[slot] = acc
             d = Decision(vm_id=vm_id, accepted=acc,
                          gpu=int(r[0]) if acc else -1,
                          start=int(r[1]) if acc else 0,
-                         tier=tier, latency_s=t_done - enq)
+                         tier=tier, latency_s=t_done - enq, batch=batch)
             self.decisions[vm_id] = d
             self.tier_occupancy[tier] += 1
             out.append(d)
@@ -882,56 +967,62 @@ class PlacementService:
         rows.block_until_ready()
 
     # -- object (ILP) tier -------------------------------------------------
-    def _drain_batch_object(self) -> List[Decision]:
+    def _drain_batch_object(self, batch: int) -> List[Decision]:
+        rec = obs_recorder.active()
+        span = rec.span if rec is not None else _no_span
+        tier = self.tier_name
+        with span("serve.drain_batch", batch=batch, tier=tier):
+            with span("serve.batch", tier=tier,
+                      rows=min(self._batch_rows, len(self.queue))):
+                out = self._object_batch(batch)
+            self._note_governor([d.latency_s for d in out])
+        return out
+
+    def _object_batch(self, batch: int) -> List[Decision]:
         tier = self.tier_name
         cl, pol = self._cluster, self._policy
         out: List[Decision] = []
         n = 0
-        rec = obs_recorder.active()
-        span = (rec.span("serve.batch", tier=tier,
-                         rows=min(self._batch_rows, len(self.queue)))
-                if rec is not None else _null_ctx())
-        with span:
-            while n < self._batch_rows:
-                nxt = self.queue.peek()
-                if nxt is None:
-                    break
-                req, enq = nxt
-                b = self._request_bucket(req)
-                if b > self._bucket:
-                    self._object_step_end()
-                    n += 1
-                    continue
-                self.queue.pop()
+        while n < self._batch_rows:
+            nxt = self.queue.peek()
+            if nxt is None:
+                break
+            req, enq = nxt
+            b = self._request_bucket(req)
+            if b > self._bucket:
+                self._object_step_end()
                 n += 1
-                if isinstance(req, Arrival):
-                    slot, _ = self._admit_slot(req)
-                    vm = self._vm_object(slot)
-                    pol.on_arrival_observed(vm, self._step_t)
-                    p0 = int(self._h_vm_pids[slot, 0])
-                    self._h_counts[p0, 1] += 1
-                    ok = pol.place(vm)
-                    if ok:
-                        self._h_counts[p0, 0] += 1
-                        self._h_accepted[slot] = True
-                        _, gpu = cl.placements[vm.vm_id]
-                        g = gpu.global_index
-                        start = int(gpu.placements[vm.vm_id][1])
-                    else:
-                        g, start = -1, 0
-                        self._rejected_step.append(vm)
-                    d = Decision(vm_id=req.vm_id, accepted=ok, gpu=g,
-                                 start=start, tier=tier,
-                                 latency_s=time.perf_counter() - enq)
-                    self.decisions[req.vm_id] = d
-                    self.tier_occupancy[tier] += 1
-                    out.append(d)
+                continue
+            self.queue.pop()
+            n += 1
+            if isinstance(req, Arrival):
+                slot, _ = self._admit_slot(req)
+                vm = self._vm_object(slot)
+                pol.on_arrival_observed(vm, self._step_t)
+                p0 = int(self._h_vm_pids[slot, 0])
+                self._h_counts[p0, 1] += 1
+                ok = pol.place(vm)
+                if ok:
+                    self._h_counts[p0, 0] += 1
+                    self._h_accepted[slot] = True
+                    _, gpu = cl.placements[vm.vm_id]
+                    g = gpu.global_index
+                    start = int(gpu.placements[vm.vm_id][1])
                 else:
-                    if req.vm_id in cl.placements:
-                        vm = cl.vms[req.vm_id]
-                        cl.release(req.vm_id)
-                        pol.on_departure(vm, self._step_t)
-        self._note_governor([d.latency_s for d in out])
+                    g, start = -1, 0
+                    self._rejected_step.append(vm)
+                d = Decision(vm_id=req.vm_id, accepted=ok, gpu=g,
+                             start=start, tier=tier,
+                             latency_s=time.perf_counter() - enq,
+                             batch=batch)
+                self.decisions[req.vm_id] = d
+                self.tier_occupancy[tier] += 1
+                out.append(d)
+            else:
+                if req.vm_id in cl.placements:
+                    vm = cl.vms[req.vm_id]
+                    cl.release(req.vm_id)
+                    pol.on_departure(vm, self._step_t)
         return out
 
     def _object_step_end(self) -> None:
@@ -948,14 +1039,6 @@ class PlacementService:
         switch = self.governor.note_batch(latencies, self.queue.fill)
         if switch is not None:
             self._switch_tier(*switch)
-
-
-class _null_ctx:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
 
 
 __all__ = ["PlacementService", "ServeConfig", "Decision", "Governor",

@@ -271,6 +271,84 @@ def test_recorder_jsonl_roundtrip_and_report(tmp_path, capsys):
     assert parsed[0]["final_baskets"] is not None
 
 
+def test_recorder_writes_only_at_close_in_order(tmp_path):
+    path = tmp_path / "rec.jsonl"
+    rec = recorder.Recorder(path, run_id="r", meta={"who": "test"})
+    for i in range(3):
+        with rec.span("s", i=i):
+            pass
+    rec.service("degrade", step=1)
+    assert not path.exists()           # nothing written while recording
+    rec.close()
+    rec.close()                        # a second close writes nothing
+    recs = [json.loads(line) for line in path.read_text().splitlines()]
+    assert [r["kind"] for r in recs] == ["meta", "span", "span", "span",
+                                         "service"]
+    assert [r["i"] for r in recs if r["kind"] == "span"] == [0, 1, 2]
+    assert recs[0]["who"] == "test" and recs[-1]["step"] == 1
+    assert all(r["run_id"] == "r" for r in recs)
+
+
+def test_span_records_carry_times_ids_and_parents(tmp_path):
+    path = tmp_path / "rec.jsonl"
+    with recorder.record(path) as rec:
+        with rec.span("outer", tag="o"):
+            with rec.span("inner") as f:
+                f["rows"] = 7          # a field known only at the end
+            with rec.span("inner"):
+                with rec.span("leaf"):
+                    pass
+        with rec.span("outer"):
+            pass
+    spans = [json.loads(line) for line in path.read_text().splitlines()]
+    spans = [r for r in spans if r["kind"] == "span"]
+    assert len({s["id"] for s in spans}) == len(spans) == 5
+    for s in spans:
+        assert s["t0"] <= s["t1"]
+        assert s["dur_s"] == s["t1"] - s["t0"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    o0, o1 = by_name["outer"]
+    i0, i1 = by_name["inner"]
+    (leaf,) = by_name["leaf"]
+    assert o0["parent"] is None and o1["parent"] is None
+    assert i0["parent"] == i1["parent"] == o0["id"]
+    assert leaf["parent"] == i1["id"]
+    assert o0["tag"] == "o" and i0["rows"] == 7
+    assert o0["t0"] <= i0["t0"] and i1["t1"] <= o0["t1"]
+    assert o0["t1"] <= o1["t0"]
+
+
+def test_span_left_by_an_exception_restores_the_parent(tmp_path):
+    with recorder.record(tmp_path / "rec.jsonl") as rec:
+        with pytest.raises(RuntimeError):
+            with rec.span("fails"):
+                raise RuntimeError("boom")
+        with rec.span("after"):
+            pass
+    spans = [r for r in rec.records if r["kind"] == "span"]
+    assert [(s["name"], s["parent"]) for s in spans] == [("after", None)]
+
+
+def test_recorder_starts_no_profiler(tmp_path, monkeypatch):
+    """The one who opens a profiler window owns it: the recorder starts
+    none, whatever the environment says."""
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: started.append(a))
+    monkeypatch.setenv("REPRO_TRACE", "1")
+    monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path / "trace"))
+    with recorder.record(tmp_path / "rec.jsonl") as rec:
+        with rec.span("s"):
+            pass
+    assert started == []
+    assert not (tmp_path / "trace").exists()
+    kinds = [json.loads(line)["kind"] for line in
+             (tmp_path / "rec.jsonl").read_text().splitlines()]
+    assert kinds == ["meta", "span"]
+
+
 def test_report_rejects_newer_schema(tmp_path):
     p = tmp_path / "future.jsonl"
     p.write_text(json.dumps({"schema": inscan.SCHEMA_VERSION + 1,

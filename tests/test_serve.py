@@ -123,9 +123,9 @@ def test_online_grmu_consolidation_migrations(trace):
     assert svc.migrations() == (res.intra_migrations, res.inter_migrations)
 
 
-def test_ilp_tier_matches_sequential_engine():
-    """The ILP (object-backend) tier replays the sequential engine's
-    ILPPolicy decisions exactly, on a mixed 5-GPU cluster."""
+def _ilp_case():
+    """A mixed 5-GPU cluster, 15 VMs: the sequential engine's ILPPolicy
+    result and the same stream as service requests."""
     from repro.core.policies import ILPPolicy
     from repro.sim.cluster import VM, make_cluster
     from repro.sim.engine import simulate
@@ -150,11 +150,153 @@ def test_ilp_tier_matches_sequential_engine():
 
     events = B.build_events(vms, cluster, step_hours=1.0, horizon=horizon)
     reqs, h = requests_from_trace(events)
-    svc = _stream(PlacementService.for_trace(
-        events, ServeConfig(tiers=("ILP",), micro_batch=8, ilp_window=4,
-                            ilp_time_limit=2.0)), reqs, h)
+    cfg = ServeConfig(tiers=("ILP",), micro_batch=8, ilp_window=4,
+                      ilp_time_limit=2.0)
+    return events, reqs, h, cfg, ref
+
+
+def test_ilp_tier_matches_sequential_engine():
+    """The ILP (object-backend) tier replays the sequential engine's
+    ILPPolicy decisions exactly, on a mixed 5-GPU cluster."""
+    events, reqs, h, cfg, ref = _ilp_case()
+    svc = _stream(PlacementService.for_trace(events, cfg), reqs, h)
     assert svc.accepted_ids() == list(ref.accepted_ids)
     assert svc.migrations() == (ref.intra_migrations, ref.inter_migrations)
+
+
+def test_ilp_tier_batches_are_recorded(tmp_path):
+    """An ILP-tier micro-batch is one ``serve.drain_batch`` root holding
+    one ``serve.batch``, numbered like the array tier's batches."""
+    events, reqs, h, cfg, ref = _ilp_case()
+    with obs_recorder.record(tmp_path / "rec.jsonl") as rec:
+        svc = _stream(PlacementService.for_trace(events, cfg), reqs, h)
+    assert svc.accepted_ids() == list(ref.accepted_ids)
+    spans = [r for r in rec.records if r["kind"] == "span"]
+    roots = [s for s in spans if s["name"] == "serve.drain_batch"]
+    assert [r["batch"] for r in roots] == list(range(len(roots)))
+    assert all(r["parent"] is None and r["tier"] == "ILP" for r in roots)
+    kids = {r["id"]: [] for r in roots}
+    for s in spans:
+        if s["parent"] in kids:
+            kids[s["parent"]].append(s["name"])
+    assert all(k == ["serve.batch"] for k in kids.values())
+    assert {d.batch for d in svc.decisions.values()} \
+        <= {r["batch"] for r in roots}
+
+
+# ---------------------------------------------------------------------------
+# The flight recorder's span tree of a served micro-batch
+# ---------------------------------------------------------------------------
+
+def _drain_batches(svc, reqs, per_submit=24):
+    """Feed ``reqs`` in slices, draining one micro-batch at a time;
+    returns the number of micro-batches drained."""
+    batches = 0
+    for i in range(0, len(reqs), per_submit):
+        for r in reqs[i:i + per_submit]:
+            assert svc.submit(r)
+        while len(svc.queue):
+            svc.drain(max_batches=1)
+            batches += 1
+    return batches
+
+
+def test_recorded_batches_form_the_span_tree(trace, tmp_path):
+    events, reqs, _ = trace
+    cfg = ServeConfig(policy="GRMU", micro_batch=16)
+    path = tmp_path / "rec.jsonl"
+    with obs_recorder.record(path):
+        svc = PlacementService.for_trace(events, cfg)
+        batches = _drain_batches(svc, reqs)
+    recs = [json.loads(line) for line in path.read_text().splitlines()]
+    spans = [r for r in recs if r["kind"] == "span"]
+    by_id = {s["id"]: s for s in spans}
+    roots = [s for s in spans if s["name"] == "serve.drain_batch"]
+    assert len(roots) == batches
+    assert [r["batch"] for r in roots] == list(range(batches))
+    assert all(r["parent"] is None and r["tier"] == "GRMU" for r in roots)
+
+    kids = {s["id"]: [] for s in spans}
+    for s in spans:
+        if s["parent"] is not None:
+            kids[s["parent"]].append(s)
+    pops = []
+    for r in roots:
+        names = [c["name"] for c in kids[r["id"]]]
+        assert names == ["serve.pop", "serve.batch", "serve.emit"]
+        pop, batch, _ = kids[r["id"]]
+        pops.append(pop)
+        inner = [c["name"] for c in kids[batch["id"]]]
+        want = (["serve.ingest"] if pop["arrivals"] else []) \
+            + ["serve.step", "serve.readback"]
+        assert inner == want
+        assert batch["rows"] == pop["rows"]
+        assert batch["arrivals"] == pop["arrivals"]
+        for c in kids[r["id"]] + kids[batch["id"]]:
+            parent = by_id[c["parent"]]
+            assert parent["t0"] <= c["t0"] <= c["t1"] <= parent["t1"]
+
+    # The pop's counters: every row and arrival of the stream, once.
+    assert sum(p["arrivals"] for p in pops) == len(svc.decisions)
+    # Every request is one row; step ends add rows of their own.
+    assert sum(p["rows"] for p in pops) >= len(reqs)
+    for p in pops:
+        assert 0 < p["rows"] <= p["batch_rows"] == svc._batch_rows
+        assert p["arrivals"] <= p["rows"]
+        assert (p["wait_sum_s"] > 0) == (p["arrivals"] > 0)
+    root_ids = {r["batch"] for r in roots}
+    assert all(d.batch in root_ids for d in svc.decisions.values())
+    # Decisions sharing a batch number came out of that batch.
+    per_batch = {}
+    for d in svc.decisions.values():
+        per_batch[d.batch] = per_batch.get(d.batch, 0) + 1
+    assert per_batch == {r["batch"]: p["arrivals"]
+                         for r, p in zip(roots, pops) if p["arrivals"]}
+
+
+def test_recorder_leaves_decisions_unchanged(trace, tmp_path):
+    events, reqs, horizon = trace
+    cfg = ServeConfig(policy="GRMU", micro_batch=16,
+                      consolidation_interval=6.0)
+
+    def run(record):
+        svc = PlacementService.for_trace(events, cfg)
+        if record:
+            with obs_recorder.record(tmp_path / "rec.jsonl"):
+                _drain_batches(svc, reqs)
+        else:
+            _drain_batches(svc, reqs)
+        svc.flush(horizon)
+        return svc
+
+    plain, recorded = run(False), run(True)
+    key = lambda svc: {v: (d.accepted, d.gpu, d.start, d.tier, d.batch)
+                       for v, d in svc.decisions.items()}
+    assert key(plain) == key(recorded)
+    assert plain.accepted_ids() == recorded.accepted_ids()
+    assert plain.migrations() == recorded.migrations()
+    assert all(d.batch >= 0 for d in plain.decisions.values())
+
+
+def test_unrecorded_batch_opens_no_span(trace, monkeypatch):
+    """With no recorder, a micro-batch asks for the recorder once and
+    reads the clock only for the decisions' latency."""
+    from repro.serve import placement
+
+    events, reqs, _ = trace
+    svc = PlacementService.for_trace(events, ServeConfig(policy="FF",
+                                                         micro_batch=16))
+    for r in reqs[:40]:
+        assert svc.submit(r)
+    assert svc.drain(max_batches=1)    # compiles outside the count
+    asks, clock = [], []
+    monkeypatch.setattr(placement.obs_recorder, "active",
+                        lambda: asks.append(1))
+    real = placement.time.perf_counter
+    monkeypatch.setattr(placement.time, "perf_counter",
+                        lambda: clock.append(1) or real())
+    out = svc.drain(max_batches=1)
+    assert out and len(asks) == 1 and len(clock) == 1
 
 
 # ---------------------------------------------------------------------------
