@@ -21,6 +21,12 @@ requirement onto each model's profile table).  A homogeneous A100 cluster
 is simply the one-model fleet with ``mid == 0`` everywhere, and reproduces
 the pre-fleet scores bit for bit.
 
+Feasibility in selection and consolidation (:func:`fit_mask`) is
+computed from the device models' slot templates, elementwise over the
+GPUs' free bitmasks; the ``fits`` table is its reference
+(tests/test_policy_core.py checks every mask) and still serves scalar
+lookups (:func:`repack_gpu`).  Scores keep their per-GPU table gathers.
+
 Scoring is integer-only (MECC uses the raw windowed counts as weights
 rather than normalized probabilities — argmax-equivalent since the
 normalizer is a positive constant) so both backends tie-break bit-for-bit
@@ -60,7 +66,8 @@ def _stack_host_tables(models: Tuple[DeviceModel, ...]) -> dict:
     Each model's §5 tables are padded to the fleet-wide maximum mask-space
     (``1 << max(num_blocks)``) and profile count, then stacked along a
     leading model axis, so every lookup is a gather by
-    ``(model_id, free_mask, profile)``.  Padded entries are never-feasible
+    ``(model_id, free_mask, profile)`` (feasibility over the GPUs is
+    computed instead: :func:`fit_mask`).  Padded entries are never-feasible
     (``fits`` False, ``assign_start`` -1, ``counts_after`` 0), so out-of-
     model profile indices and masks score below every real option.
 
@@ -188,6 +195,28 @@ def _fori(xp, n, body, init):
 # FF / BF / MCC / MECC (Algs. 6-7)
 # ---------------------------------------------------------------------------
 
+def fit_mask(xp, T, mid, free, pids):
+    """Per-GPU feasibility of a request: ``T.fits[mid, free, pids[mid]]``,
+    computed from the free bitmask instead of gathered from the table.
+
+    A profile fits a GPU iff one of its legal placements (a slot of the
+    GPU's model) lies inside the free blocks, ``(free & slot) == slot``,
+    the test the Pallas kernels make.  The slot masks and profiles are
+    Python ints of ``T.models``, so only elementwise ops run over the
+    GPUs, against the one scalar ``pids[m]`` per model: a per-GPU gather
+    from the (models, masks, profiles) table is the TPU's slowest way to
+    the same booleans.  A padded GPU (free mask 0) contains no slot, so
+    it stays unfit; a profile id the model lacks matches no slot.
+    """
+    fit = xp.zeros(free.shape, dtype=bool)
+    for m, model in enumerate(T.models):
+        hit = xp.zeros(free.shape, dtype=bool)
+        for sm, sp in zip(model.slot_masks, model.slot_profile):
+            hit = hit | (((free & sm) == sm) & (pids[m] == sp))
+        fit = fit | (hit & (mid == m) if T.num_models > 1 else hit)
+    return fit
+
+
 def mecc_weights(xp, counts):
     """MECC profile weights from windowed arrival counts.
 
@@ -227,7 +256,7 @@ def select_gpu(policy, xp, T, mid, free, pids, host_ok, mecc_w=None):
     request's per-model profile-id vector (num_models,).  Returns the GPU
     globalIndex, or -1 when no GPU is feasible (profile or host level)."""
     prof_g = pids[mid]
-    fits = T.fits[mid, free, prof_g] & host_ok
+    fits = fit_mask(xp, T, mid, free, pids) & host_ok
     scores = placement_scores(policy, xp, T, mid, free, prof_g, fits,
                               mecc_w)
     return xp.where(xp.any(fits), xp.argmax(scores), -1)
@@ -256,7 +285,7 @@ def grmu_select(xp, T, mid, free, pids, is_heavy, host_ok, basket,
     want = xp.where(is_heavy, HEAVY_BASKET, LIGHT_BASKET)
     cap = xp.where(is_heavy, heavy_cap, light_cap)
     in_basket = basket == want
-    fits = T.fits[mid, free, pids[mid]] & host_ok & in_basket
+    fits = fit_mask(xp, T, mid, free, pids) & host_ok & in_basket
     pick = first_true(xp, fits)
     pool_free = basket == POOL
     grew = (pick < 0) & (in_basket.sum() < cap) & xp.any(pool_free)
@@ -350,13 +379,15 @@ def consolidation_plan(xp, T, mid, free, cand, sole_pids, sole_cpu,
 
     def body(g, carry):
         avail, tgt_of, cpu_u, ram_u = carry
-        # Source g's profile under each candidate target's model.
-        p_t = xp.maximum(sole_pids[g, mid], 0)
+        # Source g's profile on every model, so each candidate target
+        # tests it under its own model.
+        p_t = xp.maximum(sole_pids[g], 0)
         c, r, h = sole_cpu[g], sole_ram[g], gpu_host[g]
         host_ok = ((gpu_host == h)
                    | ((cpu_u[gpu_host] + c <= cpu_cap[gpu_host])
                       & (ram_u[gpu_host] + r <= ram_cap[gpu_host])))
-        feasible = avail & (gids > g) & T.fits[mid, free, p_t] & host_ok
+        feasible = (avail & (gids > g) & fit_mask(xp, T, mid, free, p_t)
+                    & host_ok)
         tgt = first_true(xp, feasible)
         do = avail[g] & (tgt >= 0)
         tgt_c = xp.maximum(tgt, 0)
@@ -383,7 +414,7 @@ __all__ = [
     "HEAVY_PROFILE", "POOL", "HEAVY_BASKET", "LIGHT_BASKET",
     "LOWER_HALF_FREE", "UPPER_HALF_FREE", "CONSOLIDATABLE",
     "DEFAULT_MODELS", "Tables", "tables_for", "heavy_request",
-    "first_true", "mecc_weights", "placement_scores", "select_gpu",
-    "grmu_select", "defrag_target", "repack_gpu",
+    "first_true", "fit_mask", "mecc_weights", "placement_scores",
+    "select_gpu", "grmu_select", "defrag_target", "repack_gpu",
     "consolidation_candidates", "consolidation_plan",
 ]

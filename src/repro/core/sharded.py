@@ -73,8 +73,8 @@ def select_gpu_sharded(policy, T, mid, free, pids, host_ok, mecc_w,
     """Sharded FF/BF/MCC/MECC pick — decision-identical to
     ``policy_core.select_gpu``.
 
-    All operands are replicated; each shard gathers fits/scores only for
-    its contiguous ``G/K`` slice.  Feasible scores always rank strictly
+    All operands are replicated; each shard computes fits and scores only
+    for its contiguous ``G/K`` slice.  Feasible scores always rank strictly
     above infeasible sentinels (policy_core's invariant), so the local
     argmax is the local first maximizer; the cross-shard argmax over
     (score, first-shard-wins) is then the global first maximizer."""
@@ -86,7 +86,7 @@ def select_gpu_sharded(policy, T, mid, free, pids, host_ok, mecc_w,
     lfree = _local_slice(free, start, Gl)
     lprof = _local_slice(prof_g, start, Gl)
     lhost = _local_slice(host_ok, start, Gl)
-    lfits = T.fits[lmid, lfree, lprof] & lhost
+    lfits = pc.fit_mask(jnp, T, lmid, lfree, pids) & lhost
     lscores = pc.placement_scores(policy, jnp, T, lmid, lfree, lprof,
                                   lfits, mecc_w)
     lbest = jnp.argmax(lscores)
@@ -116,13 +116,11 @@ def grmu_select_sharded(T, mid, free, pids, is_heavy, host_ok, basket,
     want = jnp.where(is_heavy, pc.HEAVY_BASKET, pc.LIGHT_BASKET)
     cap = jnp.where(is_heavy, heavy_cap, light_cap)
     in_basket = basket == want
-    prof_g = pids[mid]
     lmid = _local_slice(mid, start, Gl)
     lfree = _local_slice(free, start, Gl)
-    lprof = _local_slice(prof_g, start, Gl)
     lok = (_local_slice(host_ok, start, Gl)
            & _local_slice(in_basket, start, Gl))
-    lfits = T.fits[lmid, lfree, lprof] & lok
+    lfits = pc.fit_mask(jnp, T, lmid, lfree, pids) & lok
     lpick = pc.first_true(jnp, lfits)
     cand = jax.lax.all_gather(
         jnp.where(lpick >= 0, (start + lpick).astype(jnp.int32),

@@ -10,7 +10,9 @@ them turns every pool scan into a NumPy gather over the cluster's
 free-mask vector.  The Pallas kernels in ``repro.kernels`` compute the
 same quantities directly from the model's slot templates on-chip (tables
 don't fit the TPU's vector registers as gathers, but the slot popcount
-does).
+does), and so does the engines' feasibility mask
+(``repro.core.policy_core.fit_mask``): there ``fits`` is the reference
+the slot test is checked against, not what the engines gather.
 
 Slot metadata arrays (``slot_mask_arr`` / ``slot_profile`` /
 ``slot_start``) are derived straight from the ``DeviceModel`` slot

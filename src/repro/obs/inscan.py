@@ -104,15 +104,15 @@ def arrival_reason_code(T, gmid, free, pids, host_ok, ok, grew,
 
     ``free``/``host_ok`` must be the pre-placement state and
     ``grew``/``quota_full`` the pre-growth GRMU flags.  The fleet-wide
-    slot gather runs unconditionally: it is one (G,) gather next to the
-    scoring gathers the arrival branch already does, and keeping it
-    branch-free lets XLA fuse it there — a ``lax.cond`` here costs far
-    more in conditional dispatch than the gather it would skip.  The
+    fit mask runs unconditionally: it is the same elementwise (G,) test
+    the arrival's selection makes, and keeping it branch-free lets XLA
+    fuse it there — a ``lax.cond`` here costs far more in conditional
+    dispatch than the mask it would skip.  The
     two feasibility flags come out of a single fused (G,) max reduction
     rather than two ``any`` passes (per-op dispatch in a switch branch
     is the dominant cost at this scale).
     """
-    slot = T.fits[gmid, free, pids[gmid]]
+    slot = pc.fit_mask(jnp, T, gmid, free, pids)
     best = jnp.max(jnp.where(slot, jnp.where(host_ok, 2, 1), 0))
     return reasons.arrival_code(jnp, ok, best >= 1, best >= 2,
                                 grew, quota_full)

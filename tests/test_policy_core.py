@@ -6,12 +6,17 @@ model-id vector ``mid`` and per-model profile ids.  These tests run the
 single-model (A100-40GB) fleet — ``mid`` all zero, profile ids (1,) —
 which is the paper's configuration; heterogeneous fleets are covered by
 tests/test_device_models.py and tests/test_equivalence.py."""
+import functools
+import itertools
+
 import numpy as np
 import pytest
 
 from repro.core import policy_core as pc
-from repro.core.mig import GPU, PROFILES
+from repro.core.mig import (A30_24GB, A100_40GB, DEVICE_MODELS, GPU,
+                            H100_80GB, PROFILES)
 
+jax = pytest.importorskip("jax")
 jnp = pytest.importorskip("jax.numpy")
 
 _TN = pc.tables_for(np)
@@ -181,3 +186,108 @@ def test_consolidation_plan_respects_host_headroom():
         hosts, cpu_used, np.zeros(2, np.float32), cpu_cap, big)
     assert tgt.tolist() == [1, -1]
     assert cpu_out.tolist() == [0.0, 7.0]    # resources moved with the VM
+
+
+# ---------------------------------------------------------------------------
+# fit_mask: feasibility from the free bitmask equals the fits table
+# ---------------------------------------------------------------------------
+
+_FLEETS = {m.name: (m,) for m in DEVICE_MODELS.values()}
+_FLEETS["A30+A100+H100"] = (A30_24GB, A100_40GB, H100_80GB)
+
+
+@pytest.mark.parametrize("fleet", list(_FLEETS))
+def test_fit_mask_equals_fits_table(fleet):
+    """Every reachable mask of every model, every per-model profile-id
+    combination (ids past a smaller model's profiles included), GPUs of
+    the models in random order, and padded GPUs (free 0, model 0): both
+    backends give ``T.fits[mid, free, pids[mid]]`` exactly."""
+    models = _FLEETS[fleet]
+    TN, TJ = pc.tables_for(np, models), pc.tables_for(jnp, models)
+    rng = np.random.default_rng(len(models))
+    mid = np.concatenate([np.full(m.num_masks, i, np.int32)
+                          for i, m in enumerate(models)])
+    free = np.concatenate([np.arange(m.num_masks) for m in models])
+    order = rng.permutation(mid.size)
+    pad = 8
+    mid = np.concatenate([mid[order], np.zeros(pad, np.int32)])
+    free = np.concatenate([free[order], np.zeros(pad)]).astype(np.uint8)
+    jfit = jax.jit(lambda mid, free, pids: pc.fit_mask(jnp, TJ, mid, free,
+                                                       pids))
+    for pids in itertools.product(range(TN.num_profiles),
+                                  repeat=len(models)):
+        pids = np.asarray(pids, np.int32)
+        want = TN.fits[mid, free, pids[mid]]
+        assert not want[-pad:].any()
+        got_np = pc.fit_mask(np, TN, mid, free, pids)
+        got_j = np.asarray(jfit(jnp.asarray(mid),
+                                jnp.asarray(free.astype(np.int32)),
+                                jnp.asarray(pids)))
+        np.testing.assert_array_equal(got_np, want)
+        np.testing.assert_array_equal(got_j, want)
+
+
+def _table_gathers(jaxpr, table):
+    """Per-GPU gathers (non-scalar output) whose operand has ``table``'s
+    shape and dtype, in ``jaxpr`` and every sub-jaxpr (scan, while, cond
+    bodies)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        src = eqn.invars[0].aval if eqn.invars else None
+        if (eqn.primitive.name == "gather"
+                and (src.shape, src.dtype) == (table.shape, table.dtype)
+                and eqn.outvars[0].aval.shape != ()):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _table_gathers(sub, table)
+    return found
+
+
+def _grmu_call(T):
+    return lambda mid, free, pids, ok, basket: pc.grmu_select(
+        jnp, T, mid, free, pids, False, ok, basket, 3, 5)[0]
+
+
+def _ff_call(T):
+    return lambda mid, free, pids, ok, basket: pc.select_gpu(
+        pc.FF, jnp, T, mid, free, pids, ok)
+
+
+def _table_call(T):
+    """The per-GPU table gather itself: the check must see this one."""
+    return lambda mid, free, pids, ok, basket: T.fits[mid, free, pids[mid]]
+
+
+@pytest.mark.parametrize("fleet", ["A100-40GB", "A30+A100+H100"])
+@pytest.mark.parametrize("call,expect", [(_grmu_call, 0), (_ff_call, 0),
+                                         (_table_call, 1)])
+def test_selection_does_not_gather_the_fits_table(fleet, call, expect):
+    """GRMU and FF selection on the ``jnp`` tables test fit elementwise:
+    no gather from an operand of ``T.fits``'s shape."""
+    T = pc.tables_for(jnp, _FLEETS[fleet])
+    G = 64
+    args = (jnp.zeros(G, jnp.int32), jnp.zeros(G, jnp.int32),
+            jnp.zeros(T.num_models, jnp.int32), jnp.ones(G, bool),
+            jnp.zeros(G, jnp.int32))
+    jaxpr = jax.make_jaxpr(call(T))(*args).jaxpr
+    assert len(_table_gathers(jaxpr, T.fits)) == expect
+
+
+@pytest.mark.parametrize("policy", ["FF", "BF", "MCC", "MECC", "GRMU"])
+def test_replay_scan_does_not_gather_the_fits_table_per_gpu(policy):
+    """The whole replay scan (GRMU with defrag and consolidation) keeps
+    only scalar lookups of ``T.fits`` (the defrag repack's)."""
+    from repro.core import batched as B
+    from repro.core.bucketing import pad_events
+    from repro.workload.alibaba import TraceConfig, generate
+
+    cluster, vms = generate(TraceConfig(scale=0.02, seed=0))
+    pv = pad_events(B.build_events(vms, cluster))
+    cfg = (dict(defrag=True, consolidation_interval=6.0)
+           if policy == "GRMU" else {})
+    st = B.replay_statics(pv, getattr(B, policy), score_backend="tables",
+                          **cfg)
+    jaxpr = jax.make_jaxpr(functools.partial(B._scan_fn, st))(
+        B.init_state(pv, st), B.trace_arrays(pv), jnp.int32(3)).jaxpr
+    fits = pc.tables_for(jnp, st.models).fits
+    assert _table_gathers(jaxpr, fits) == []
