@@ -71,6 +71,7 @@ scans resolve ties by lowest globalIndex.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -97,6 +98,7 @@ from . import policy_core as pc
 from . import compile_cache
 from ..obs import inscan as obs_inscan
 from ..obs import reasons as obs_reasons
+from ..obs import recorder as obs_recorder
 
 # Policy ids re-exported for callers of this module.  The old engine's
 # "GRMU-DB" policy id is gone: the DB point is GRMU with defrag=False,
@@ -1003,17 +1005,35 @@ def make_replay(events: EventTrace, policy: int, **cfg) -> Callable:
 
     The compiled executable is shared across traces with the same bucket
     shapes and (policy, cfg, model-set) — replaying a new trace from an
-    already-seen bucket skips XLA entirely."""
+    already-seen bucket skips XLA entirely.
+
+    With a recorder installed (``repro.obs.recorder``), the statics (the
+    fleet's models among them), the compiled function's lookup and the
+    trace's upload run inside a ``replay.prepare`` span whose counters
+    size the fleet: ``models``, padded ``gpus``, ``pid_cols`` (profile
+    columns per VM) and ``slot_tests``, the (GPU, slot) tests that one
+    arrival's :func:`policy_core.fit_mask` makes (every GPU against each
+    model's slots)."""
     compile_cache.ensure_persistent_cache()
-    st = replay_statics(events, policy, **cfg)
-    jfn = _jitted_run(st)
-    tr = {k: jnp.asarray(v) for k, v in trace_arrays(events).items()}
+    rec = obs_recorder.active()
+    with (contextlib.nullcontext() if rec is None
+          else rec.span("replay.prepare", **_fleet_counters(events))):
+        st = replay_statics(events, policy, **cfg)
+        jfn = _jitted_run(st)
+        tr = {k: jnp.asarray(v) for k, v in trace_arrays(events).items()}
 
     def run(heavy_capacity):
         return jfn(init_state(events, st), tr,
                    jnp.asarray(heavy_capacity, jnp.int32))
 
     return run
+
+
+def _fleet_counters(events: EventTrace) -> Dict[str, int]:
+    G = len(events.gpu_model_id)
+    return dict(models=len(events.models), gpus=G,
+                slot_tests=G * sum(m.num_slots for m in events.models),
+                pid_cols=int(events.vm_pids.shape[1]))
 
 
 def replay(events: EventTrace, policy: int,

@@ -29,7 +29,7 @@ from repro.obs import inscan, reasons, recorder, report
 from repro.sim.engine import simulate
 from repro.sim import metrics
 from test_bucketing import POLICIES, assert_same_replay
-from test_equivalence import hetero_scenario
+from test_equivalence import hetero_scenario, random_scenario
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -364,6 +364,58 @@ def test_unrecorded_chunked_replay_has_no_spans(tmp_path):
     assert recorder.active() is None
     ST.replay_chunked(ev, B.FF, cap, chunk_events=64)
     assert list(tmp_path.iterdir()) == []
+
+
+def _fleet_events(fleet):
+    """A padded trace on a one-model (A100-40GB) or three-model (A30,
+    A100-40GB, H100-80GB) fleet."""
+    scenario = random_scenario if fleet == "one" else hetero_scenario
+    cluster, vms = scenario(3)
+    return pad_events(B.build_events(vms, cluster))
+
+
+@pytest.mark.parametrize("fleet", ["one", "three"])
+def test_make_replay_records_prepare_span(tmp_path, fleet):
+    """Under a recorder, ``make_replay`` records one ``replay.prepare``
+    span whose counters size the fleet: ``slot_tests`` is the (GPU,
+    slot) tests one arrival's ``fit_mask`` makes, every padded GPU
+    against each model's slots."""
+    pv = _fleet_events(fleet)
+    M = len(pv.models)
+    assert M == (1 if fleet == "one" else 3)
+    path = tmp_path / "run.jsonl"
+    with recorder.record(path):
+        B.make_replay(pv, B.GRMU)
+    spans = [r for r in map(json.loads, open(path))
+             if r["kind"] == "span"]
+    assert [s["name"] for s in spans] == ["replay.prepare"]
+    G = len(pv.gpu_model_id)
+    assert G > pv.num_gpus                   # padded
+    s = spans[0]
+    assert (s["models"], s["gpus"], s["pid_cols"]) == (M, G, M)
+    assert s["slot_tests"] == G * sum(len(m.slot_masks) for m in pv.models)
+    assert s["parent"] is None and s["dur_s"] > 0
+
+
+def test_unrecorded_make_replay_emits_nothing_and_decides_alike(
+        tmp_path, monkeypatch):
+    """With no recorder nothing is emitted; with one, the replay's
+    outputs are the same arrays."""
+    pv = _fleet_events("three")
+    cap = B.default_heavy_capacity(pv)
+    emitted = []
+    monkeypatch.setattr(recorder.Recorder, "emit",
+                        lambda self, kind, **f: emitted.append(kind))
+    assert recorder.active() is None
+    plain = jax.device_get(B.make_replay(pv, B.GRMU, **GRMU_KW)(cap))
+    assert emitted == []
+    with recorder.record(tmp_path / "run.jsonl"):
+        recorded = jax.device_get(
+            B.make_replay(pv, B.GRMU, **GRMU_KW)(cap))
+    assert "span" in emitted
+    assert plain.keys() == recorded.keys()
+    for k in plain:
+        assert np.array_equal(plain[k], recorded[k]), k
 
 
 # ---------------------------------------------------------------------------
